@@ -26,11 +26,11 @@ service-smoke:     ## RandService burst bench rows only (service/* in BENCH_thro
 
 fleet:             ## 2-shard wire fleet (pipelined binary clients, coalescing+pools on): kill-mid-burst failover, digest vs no-fault, union replay
 	rm -rf /tmp/repro-fleet
-	$(PY) -m repro.service --fleet 2 --burst 256 --tenants 64 \
+	JAX_PLATFORMS=cpu $(PY) -m repro.service --fleet 2 --burst 256 --tenants 64 \
 	    --journal-dir /tmp/repro-fleet --fault-plan kill@128 --verify-replay
 
 fleet-smoke:       ## fleet bench rows (binary/json pair, hammer/unique/kill; fleet/* in BENCH_throughput.json)
-	$(PY) -m benchmarks.throughput fleet
+	JAX_PLATFORMS=cpu $(PY) -m benchmarks.throughput fleet
 
 inference:         ## continuous batcher: fused/xla parity run, then kill-mid-run + journal replay, digest vs no-fault
 	rm -rf /tmp/repro-inference && mkdir -p /tmp/repro-inference

@@ -17,9 +17,10 @@ and reports it as a fraction of that bound, per variant:
     ring (allocation-free steady state).
 
 Bandwidth comes from a table of known TPU/GPU parts keyed on
-``device_kind``; on anything unrecognized (CPU CI) a measured jitted
-stream (read + write of a ~64 MiB buffer) stands in, tagged
-``measured:`` so rows are honest about the bound's provenance.  Every
+``device_kind``; a TPU that is not in the table is an error.  Only off
+the TPU (CPU CI) does a measured jitted stream (read + write of a
+~64 MiB buffer) stand in, tagged ``measured:`` so rows are honest about
+the bound's provenance.  Every
 row lands in BENCH_throughput.json with ``roofline_pct`` and the paper's
 655 GSample/s reference.
 
@@ -28,16 +29,10 @@ single-window rate and donated-depth >= the same ratio of producer_d1 —
 i.e. the optimized paths never regress below their OWN baseline tier
 (donated rings race the producer machinery, not raw jit dispatch, which
 a 1-CPU container could never honor).
-
-``dryrun_rows`` keeps the previous deliverable: re-printing the
-experiments/dryrun model-roofline artifacts when present.
 """
 from __future__ import annotations
 
 import functools
-import glob
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +41,6 @@ from benchmarks.common import (bytes_per_sample, row, time_fn_stats,
                                write_bench_json)
 from repro.core import engine
 from repro.runtime.blocks import BlockService, donation_supported
-
-DRYRUN_DIR = os.environ.get("DRYRUN_DIR", "experiments/dryrun")
 
 PAPER_GSAMPLES = 655.0   # U250 @ 2560 streams, paper Fig. 6
 CHECK_RATIO = 0.75       # CI gate: optimized >= 75% of its baseline tier
@@ -79,12 +72,17 @@ def _measured_bandwidth(nbytes: int = 1 << 26) -> float:
 
 
 def detect_bandwidth() -> tuple:
-    """(bytes_per_s, source) for device 0 — part table, else measured."""
-    kind = jax.devices()[0].device_kind
+    """(bytes_per_s, source) for device 0 — part table, else (off-TPU
+    only) measured."""
+    dev = jax.devices()[0]
+    kind = dev.device_kind
     low = kind.lower()
     for sub, bw in KNOWN_BW:
         if sub in low:
             return bw, f"table:{kind}"
+    if dev.platform == "tpu":
+        raise ValueError(f"TPU device_kind {kind!r} is not in KNOWN_BW; "
+                         f"add its published HBM bandwidth")
     return _measured_bandwidth(), f"measured:{kind}"
 
 
@@ -103,7 +101,7 @@ def _producer_pass(svc: BlockService, name: str, t: int, n_blocks: int,
 def run(out, records=None, *, s: int = 2048, t: int = 2048,
         n_blocks: int = 8, fuse_widths=(4, 8), depths=(2, 4),
         cases=CASES, iters: int = 3) -> None:
-    """The engine roofline sweep + the legacy dryrun reprint."""
+    """The engine roofline sweep."""
     bw, bw_src = detect_bandwidth()
     out(row("roofline/bandwidth", 0.0,
             f"{bw / 1e9:.0f} GB/s ({bw_src}); paper ref "
@@ -181,8 +179,6 @@ def run(out, records=None, *, s: int = 2048, t: int = 2048,
                     time_fn_stats(one, iters=p_iters, warmup=1),
                     n_blocks * s * t, depth=d, donate=True)
 
-    dryrun_rows(out)
-
 
 def smoke(out=print, records=None) -> None:
     """CI-sized roofline: two classes, small blocks, one fused width and
@@ -225,38 +221,12 @@ def check(records) -> list:
     return failures
 
 
-def dryrun_rows(out) -> None:
-    """Legacy deliverable (g): model-roofline rows from the dry-run
-    artifacts in experiments/dryrun/*.json, when present."""
-    files = sorted(glob.glob(os.path.join(DRYRUN_DIR, "*.json")))
-    if not files:
-        out(row("roofline/dryrun/none", 0.0,
-                "no dry-run artifacts; run python -m repro.launch.dryrun"))
-        return
-    for f in files:
-        with open(f) as fh:
-            rep = json.load(fh)
-        tag = os.path.basename(f)[:-5]
-        if rep.get("skipped"):
-            out(row(f"roofline/{tag}", 0.0, "SKIP " + rep["skipped"][:60]))
-            continue
-        if rep.get("error"):
-            out(row(f"roofline/{tag}", 0.0, "FAIL " + rep["error"][:80]))
-            continue
-        r = rep["roofline"]
-        mem = rep["memory"].get("total_bytes_per_device", 0) / 2 ** 30
-        out(row(
-            f"roofline/{tag}", 0.0,
-            f"compute={r['compute_s'] * 1e3:.1f}ms"
-            f" memory={r['memory_s'] * 1e3:.1f}ms"
-            f" collective={r['collective_s'] * 1e3:.1f}ms"
-            f" bottleneck={r['bottleneck'].replace('_s', '')}"
-            f" useful_ratio={r['useful_flops_ratio']:.2f}"
-            f" mem/dev={mem:.2f}GiB"))
-
-
 if __name__ == "__main__":
     import sys
+
+    from repro import compile_cache
+
+    compile_cache.enable()
     argv = sys.argv[1:]
     do_check = "--check" in argv
     full = "--full" in argv

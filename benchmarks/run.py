@@ -9,6 +9,9 @@ import time
 
 def main() -> None:
     from benchmarks import apps, comparison, quality, roofline, throughput
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     rows = []
     records = []

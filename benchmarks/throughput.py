@@ -750,6 +750,10 @@ SMOKES = {
 
 if __name__ == "__main__":
     import sys
+
+    from repro import compile_cache
+
+    compile_cache.enable()
     only = sys.argv[1:]
     unknown = set(only) - set(SMOKES)
     if unknown:

@@ -229,17 +229,15 @@ def _faithful_tile_states(plan: GenPlan, block_t: int, n_tiles: int,
 
     When ``xs0`` is given — (S, 4) states already advanced to plan.ctr,
     carrying GLOBAL substream identity (the sharded case) — tile states
-    are derived from it with relative traced jumps instead of rebuilding
-    the lane table from local indices.
+    are chained from it in-graph, each one static ``block_t`` jump past
+    the last, instead of rebuilding the lane table from local indices.
     """
     S = plan.num_streams
     if xs0 is not None:
-        def tile_from(i):
-            off = u64.mul32_wide(i, U32(block_t))
-            return xorshift.jump_traced(xs0, off[0], off[1])  # (S, 4)
-
-        states = jax.vmap(tile_from)(jnp.arange(n_tiles, dtype=U32))
-        return jnp.transpose(states, (0, 2, 1))  # (n_tiles, 4, S)
+        states = [xs0]
+        for _ in range(n_tiles - 1):
+            states.append(xorshift.jump_static(states[-1], block_t))
+        return jnp.transpose(jnp.stack(states), (0, 2, 1))  # (n_tiles, 4, S)
     if plan.offset is not None:
         # Vectorized GF(2) jumps over the WHOLE lane table: n_tiles batched
         # matvecs instead of an O(S * n_tiles) python-int jump loop
@@ -642,7 +640,6 @@ def generate_sharded(plan: GenPlan, *, mesh: Optional[jax.sharding.Mesh] = None,
         >>> bool(np.array_equal(np.asarray(out), np.asarray(direct)))
         True
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if axis_names is None:
@@ -676,9 +673,9 @@ def generate_sharded(plan: GenPlan, *, mesh: Optional[jax.sharding.Mesh] = None,
     def local(hh, hl, *rest):
         lp = dataclasses.replace(plan, h=(hh, hl))
         lxs0 = rest[0] if rest else None
-        return generate(lp, backend=backend or "xla", block_t=block_t,
+        return generate(lp, backend=backend, block_t=block_t,
                         block_s=block_s, xs0=lxs0)
 
-    out = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=P(None, axes), check_rep=False)(*operands)
+    out = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                        out_specs=P(None, axes), check_vma=False)(*operands)
     return out[:, :S]

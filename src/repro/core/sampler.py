@@ -247,9 +247,12 @@ def uniform_from_bits(bits: jnp.ndarray, dtype=jnp.float32) -> jnp.ndarray:
     """U[0, 1) from the top 24 bits (matches stream.uniform exactly).
 
     Always computed at float32 resolution; bfloat16 output is the f32
-    value rounded once at the end (the bandwidth-halving cast).
+    value rounded once at the end (the bandwidth-halving cast).  The
+    24-bit value converts through int32 (exact, it is < 2**24): Mosaic
+    has no direct uint32 -> float32 cast.
     """
-    u = (bits >> U32(8)).astype(jnp.float32) * np.float32(2.0 ** -24)
+    u = ((bits >> U32(8)).astype(jnp.int32).astype(jnp.float32)
+         * np.float32(2.0 ** -24))
     return u if dtype == jnp.float32 else u.astype(dtype)
 
 

@@ -209,6 +209,16 @@ def jump_batch(states: np.ndarray, n: int) -> np.ndarray:
     return states
 
 
+def _matvec_traced(mat, s: jnp.ndarray) -> jnp.ndarray:
+    """One packed GF(2) matvec in-graph: mat (128, 4); s (..., 4)."""
+    acc = jnp.bitwise_and(mat, s[..., None, :])  # (..., 128, 4)
+    pc = jax.lax.population_count(acc).astype(U32)
+    parity = jnp.sum(pc, axis=-1) & U32(1)  # (..., 128)
+    bitpos = jnp.arange(32, dtype=U32)
+    bits = parity.reshape(parity.shape[:-1] + (4, 32))
+    return jnp.sum(bits << bitpos, axis=-1, dtype=U32)
+
+
 def jump_traced(state: jnp.ndarray, n_hi: jnp.ndarray, n_lo: jnp.ndarray
                 ) -> jnp.ndarray:
     """Traced jump-ahead by a dynamic 64-bit count (n_hi, n_lo).
@@ -219,21 +229,25 @@ def jump_traced(state: jnp.ndarray, n_hi: jnp.ndarray, n_lo: jnp.ndarray
     """
     mats = jnp.asarray(_packed_pow2_matrices(64))  # (64, 128, 4)
 
-    def matvec(mat, s):
-        # mat: (128, 4); s: (..., 4) -> (..., 4)
-        acc = jnp.bitwise_and(mat, s[..., None, :])  # (..., 128, 4)
-        pc = jax.lax.population_count(acc).astype(U32)
-        parity = jnp.sum(pc, axis=-1) & U32(1)  # (..., 128)
-        bitpos = jnp.arange(32, dtype=U32)
-        bits = parity.reshape(parity.shape[:-1] + (4, 32))
-        words = jnp.sum(bits << bitpos, axis=-1, dtype=U32)
-        return words
-
     def body(k, s):
         bit = jnp.where(k < 32, (n_lo >> k.astype(U32)) & U32(1),
                         (n_hi >> (k.astype(U32) - U32(32))) & U32(1))
-        jumped = matvec(mats[k], s)
+        jumped = _matvec_traced(mats[k], s)
         return jnp.where((bit == 1)[..., None] if bit.ndim else bit == 1,
                          jumped, s)
 
     return jax.lax.fori_loop(0, 64, body, state)
+
+
+def jump_static(state: jnp.ndarray, n: int) -> jnp.ndarray:
+    """In-graph jump of a (..., 4) state table by a static count ``n``:
+    one matvec per set bit of ``n`` (``jump_traced`` does 64)."""
+    mats = _packed_pow2_matrices(64)
+    n = int(n)
+    k = 0
+    while n:
+        if n & 1:
+            state = _matvec_traced(jnp.asarray(mats[k]), state)
+        n >>= 1
+        k += 1
+    return state

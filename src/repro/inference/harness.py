@@ -34,6 +34,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro import compile_cache
 from repro.runtime import fault
 from repro.service import audit
 from repro.inference.scheduler import (ContinuousBatcher, RunResult,
@@ -141,6 +142,7 @@ def main(argv=None) -> int:
     p.add_argument("--json", action="store_true",
                    help="print the full JSON report")
     args = p.parse_args(argv)
+    compile_cache.enable()
 
     config = ScheduleConfig(
         capacity=args.batch, vocab=args.vocab, sequences=args.sequences,

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import sampler as sampler_mod, splitmix
 from repro.core.u64 import U32
 from repro.runtime import blocks, fault
 from repro.service import audit, tenants
@@ -138,20 +139,14 @@ class SyntheticLogitModel:
         self.capacity = capacity
         self.vocab = vocab
         P1, P2 = U32(0x9E3779B1), U32(0x85EBCA77)
-        sc = np.float32(scale * 2.0 ** -24)
-
-        def fmix32(x):
-            x = x ^ (x >> U32(16))
-            x = x * U32(0x85EBCA6B)
-            x = x ^ (x >> U32(13))
-            x = x * U32(0xC2B2AE35)
-            return x ^ (x >> U32(16))
+        sc = np.float32(scale)
 
         def logits(seq_hash, position):
             col = jnp.arange(vocab, dtype=jnp.uint32).reshape(1, vocab)
             x = (seq_hash.reshape(capacity, 1)
                  ^ (position.reshape(capacity, 1) * P1) ^ (col * P2))
-            return (fmix32(x) >> U32(8)).astype(jnp.float32) * sc
+            # u * scale rounds once, exactly as (bits >> 8) * (scale / 2**24)
+            return sampler_mod.uniform_from_bits(splitmix.fmix32(x)) * sc
 
         self._fn = jax.jit(logits)
 

@@ -80,14 +80,18 @@ def fused_dropout_2d(x: jnp.ndarray, h, x0, ctr0, rate: float,
     Element (m, n) keeps iff ThundeRiNG bits for flat counter
     ctr0 + m*N + n are below (1-rate)*2^32; kept values scale by 1/(1-rate).
     Bit-exact with ref.fused_dropout for any tiling.
+
+    The row tile is the whole of M when M <= block_m, else block_m
+    rounded down to the dtype's sublane multiple (8 for f32, 16 for
+    bf16); M is padded up to a tile multiple and the pad rows dropped.
     """
     if rate <= 0.0:
         return x
     M, N = x.shape
-    bm = min(block_m, M)
-    while M % bm:
-        bm -= 1  # fall back to a divisor (shapes here are multiples of 8)
-    n_tiles = M // bm
+    sub = sampler.sublane_multiple(x.dtype)
+    bm = M if M <= block_m else max(sub, block_m - block_m % sub)
+    Mp = -(-M // bm) * bm
+    n_tiles = Mp // bm
     tile_elems = bm * N
 
     # Per-tile pre-advanced base roots: A(ctr0 + i*tile) x0 + C(...)
@@ -109,30 +113,30 @@ def fused_dropout_2d(x: jnp.ndarray, h, x0, ctr0, rate: float,
     thresh = keep_threshold(rate)
     scale = 1.0 / (1.0 - rate)
 
-    col = lambda v: v.reshape(n_tiles, 1)
+    # Per-tile scalars ride as (n_tiles, 1, 1) arrays so each block's
+    # last two dims equal the array's — the only (1, 1) block TPU
+    # tiling admits.
+    per_tile = lambda v: v.reshape(n_tiles, 1, 1)
     one = lambda v: jnp.broadcast_to(v, (1, 1))
+    tile_spec = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))
+    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
 
     out = pl.pallas_call(
         functools.partial(_kernel, thresh=thresh, scale=scale, n_cols=N),
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((bm, N), lambda i: (i, 0)),      # x
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),       # rb hi
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),       # rb lo
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),       # ctr base hi
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),       # ctr base lo
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),       # h hi
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),       # h lo
-            pl.BlockSpec((bm, N), lambda i: (0, 0)),      # A hi
-            pl.BlockSpec((bm, N), lambda i: (0, 0)),      # A lo
-            pl.BlockSpec((bm, N), lambda i: (0, 0)),      # C hi
-            pl.BlockSpec((bm, N), lambda i: (0, 0)),      # C lo
+            tile_spec, tile_spec,                          # rb hi, lo
+            tile_spec, tile_spec,                          # ctr base hi, lo
+            whole((1, 1)), whole((1, 1)),                  # h hi, lo
+            whole((bm, N)), whole((bm, N)),                # A hi, lo
+            whole((bm, N)), whole((bm, N)),                # C hi, lo
         ],
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
         interpret=interpret,
-    )(x, col(rb[0]), col(rb[1]),
-      col(base[0]), col(base[1]),
+    )(jnp.pad(x, ((0, Mp - M), (0, 0))), per_tile(rb[0]), per_tile(rb[1]),
+      per_tile(base[0]), per_tile(base[1]),
       one(h[0]), one(h[1]),
       At[0], At[1], Ct[0], Ct[1])
-    return out
+    return out[:M]

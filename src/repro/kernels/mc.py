@@ -101,18 +101,21 @@ def _launch(kernel, roots, ctr_rows, hx, hy, out_dtype, *, block_t, block_s,
     grid = (Tp // bt, Sp // bs)
     col_spec = pl.BlockSpec((bt, 1), lambda i, j: (i, 0))
     row_spec = pl.BlockSpec((1, bs), lambda i, j: (0, j))
+    # Partials are (T_tiles, 1, Sp): each tile's (1, bs) row is a block
+    # whose second-to-last dim spans the whole (unit) axis, which TPU
+    # tiling admits; a (1, bs) block of a (T_tiles, Sp) array it refuses.
     partials = pl.pallas_call(
         functools.partial(kernel, block_t=bt, num_steps=T),
         grid=grid,
         in_specs=[col_spec, col_spec, col_spec, col_spec,
                   row_spec, row_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((1, bs), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], Sp), out_dtype),
+        out_specs=pl.BlockSpec((None, 1, bs), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 1, Sp), out_dtype),
         interpret=interpret,
     )(pad_col(roots[0]), pad_col(roots[1]),
       pad_col(ctr_rows[0]), pad_col(ctr_rows[1]),
       pad_row(hx[0]), pad_row(hx[1]), pad_row(hy[0]), pad_row(hy[1]))
-    return partials[:, :S]
+    return partials[:, 0, :S]
 
 
 def _plan_rows(px):

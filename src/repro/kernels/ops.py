@@ -140,7 +140,9 @@ def estimate_pi(*, seed: int, num_lanes: int, draws_per_lane: int,
         partials = _mc.pi_partials_from_plans(px, py, block_t=block_t,
                                               block_s=block_s,
                                               interpret=_use_interpret())
-        inside = jnp.sum(partials.astype(jnp.float32))
+        # exact int32 per-lane counts, then the ref path's float32 sum
+        lanes = jnp.sum(partials, axis=0)
+        inside = jnp.sum(lanes.astype(jnp.float32))
     else:
         from repro.kernels import ref
         ux = engine.sample(px, sampler="uniform", backend="ref")

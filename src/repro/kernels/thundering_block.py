@@ -84,8 +84,7 @@ def _faithful_kernel(root_hi_ref, root_lo_ref, h_hi_ref, h_lo_ref,
     def body(t, carry):
         x, y, z, w = carry
         x, y, z, w = xorshift.step_xyzw(x, y, z, w)
-        row = pl.load(bits_ref, (pl.dslice(t, 1), slice(None)))
-        pl.store(bits_ref, (pl.dslice(t, 1), slice(None)), row ^ w[None, :])
+        bits_ref[pl.ds(t, 1), :] = bits_ref[pl.ds(t, 1), :] ^ w[None, :]
         return x, y, z, w
 
     jax.lax.fori_loop(0, block_t, body, (x, y, z, w))

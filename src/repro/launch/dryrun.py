@@ -128,7 +128,7 @@ def _compile_cell(cfg, shape_name: str, mesh,
     batch_specs = input_specs(cfg, shape_name, model)
     bshard = steps_mod.batch_sharding(cfg, batch_specs, mesh)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if spec.kind == "train":
             opt_shapes = jax.eval_shape(adamw_init, params_shapes)
             oshard = steps_mod.opt_sharding_like(pshard, mesh)

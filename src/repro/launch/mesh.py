@@ -7,19 +7,12 @@ platform devices BEFORE first jax init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh_auto(shape, axes):
-    """jax.make_mesh with Auto axis types where the jax version has them.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; on older versions
-    plain ``make_mesh`` already defaults every axis to Auto semantics.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+    """jax.make_mesh with every axis Auto (XLA propagates shardings)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import stream as tstream
+from repro.core import sampler as sampler_mod, stream as tstream
 from repro.models import layers as L
 from repro.models.common import ArchConfig
 
@@ -40,7 +40,7 @@ def router_probs(x, router_w, rng: Optional[tstream.ThunderStream],
     if rng is not None and jitter > 0:
         bits = L.dropout_bits((rng.h_hi, rng.h_lo), (rng.ctr_hi, rng.ctr_lo),
                               x.shape)
-        u = (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(2.0 ** -24)
+        u = sampler_mod.uniform_from_bits(bits)
         x = x * (1.0 + jitter * (2.0 * u - 1.0)).astype(x.dtype)
     logits = jnp.einsum("gsd,de->gse", x, router_w.astype(x.dtype),
                         preferred_element_type=jnp.float32)

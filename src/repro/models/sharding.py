@@ -32,8 +32,9 @@ TP_FALLBACK = ("embed",)
 FSDP_PRIORITY = ("embed", "vocab", "f", "ssm_inner", "head")
 
 
-def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+def mesh_axis_sizes(mesh) -> Dict[str, int]:
+    """Axis name -> size, for a concrete ``Mesh`` or an ``AbstractMesh``."""
+    return dict(mesh.shape)
 
 
 def fsdp_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -184,17 +185,12 @@ def context_parallel_attention(mesh_or_none, n_kv: int, n_rep: int) -> bool:
     return (n_kv % ms != 0) and (n_rep % ms != 0)
 
 
-def ambient_mesh() -> Optional[Mesh]:
-    """The mesh installed by ``with mesh:`` (legacy thread resources), or
-    None outside any mesh context (e.g. single-device tests)."""
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+def ambient_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The mesh installed by ``with jax.set_mesh(mesh):``, or None outside
+    any mesh context (e.g. single-device tests).  Abstract, so it can be
+    read while tracing."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def prefer_seq_gather(cfg, batch: int, seq: int) -> bool:

@@ -16,10 +16,13 @@ drive this path).
 
 ``--fleet N`` runs the same burst against an N-shard subprocess fleet
 (``repro.service.fleet``) over the socket transport instead of an
-in-process server; ``--fault-plan`` scripts the adversary:
+in-process server; ``--fault-plan`` scripts the adversary.  Shards are
+not pinned to chips, so ``JAX_PLATFORMS`` must name their platforms
+without ``tpu`` (``fleet.Fleet`` refuses otherwise):
 
-  PYTHONPATH=src python -m repro.service --fleet 2 --burst 1024 \\
-      --tenants 256 --journal-dir /tmp/fleet --fault-plan kill@512
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.service --fleet 2 \\
+      --burst 1024 --tenants 256 --journal-dir /tmp/fleet \\
+      --fault-plan kill@512
 
 Shards coalesce (``--fleet-max-batch``), keep standing producer pools
 (``--fleet-hot``), and speak binary v2 wire frames to a pipelined
@@ -36,6 +39,7 @@ import json
 import sys
 import time
 
+from repro import compile_cache
 from repro.service import audit
 from repro.service.burst import make_requests, run_burst
 from repro.service.server import (RandServer, ServerConfig,
@@ -203,6 +207,7 @@ def main(argv=None) -> int:
 
     if args.fleet:
         return _run_fleet(args)
+    compile_cache.enable()
 
     deterministic = args.submit_threads == 0
     cfg = ServerConfig(

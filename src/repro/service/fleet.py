@@ -66,6 +66,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import compile_cache
 from repro.runtime.fault import FaultInjector, FaultPlan
 from repro.service import transport
 from repro.service.frontend import RandRequest
@@ -179,10 +180,26 @@ class Fleet:
     ``shard<i>.log``.  ``fence(i)`` is the STONITH step: SIGKILL + wait,
     guaranteeing the child's journal flock is released before a peer
     adopts it.
+
+    Shards inherit this process's environment, ``JAX_PLATFORMS``
+    included.  A TPU chip belongs to one process at a time and shards are
+    not pinned to chips, so the fleet refuses to start unless
+    ``JAX_PLATFORMS`` names the shards' platforms and ``tpu`` is not
+    among them.  The check reads the environment only: it initializes no
+    JAX backend in this process.
     """
 
     def __init__(self, config: FleetConfig,
                  fault_plan: Optional[FaultPlan] = None):
+        platforms = os.environ.get("JAX_PLATFORMS", "")
+        names = {p.strip() for p in platforms.split(",") if p.strip()}
+        if not names or "tpu" in names:
+            raise RuntimeError(
+                f"Fleet shards are separate JAX processes that inherit "
+                f"JAX_PLATFORMS={platforms!r}, and a TPU chip belongs to one "
+                f"process at a time: shards reaching for a chip held by "
+                f"this process or by each other would fail or hang. Set "
+                f"JAX_PLATFORMS to platforms without 'tpu' (e.g. cpu).")
         self.config = config
         self.fault_plan = fault_plan or FaultPlan()
         os.makedirs(config.journal_dir, exist_ok=True)
@@ -193,7 +210,6 @@ class Fleet:
         env = dict(os.environ)
         env["PYTHONPATH"] = src_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         for i in range(config.num_shards):
             cmd = [sys.executable, "-m", "repro.service.fleet", "--serve",
                    "--shard", str(i), "--seed", str(config.seed),
@@ -752,6 +768,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if not args.serve:
         ap.error("--serve is the only mode (spawned by fleet.Fleet)")
+    compile_cache.enable()
     return serve_shard(args)
 
 

@@ -109,7 +109,7 @@ bshard = steps_mod.batch_sharding(cfg, batch, mesh)
 opt = jax.eval_shape(adamw_init, params)
 oshard = steps_mod.opt_sharding_like(pshard, mesh)
 ts = steps_mod.make_train_step(model, microbatches=2)
-with mesh:
+with jax.set_mesh(mesh):
     lowered = jax.jit(ts, in_shardings=(pshard, oshard, bshard,
                                         NamedSharding(mesh, P())),
                       out_shardings=(pshard, oshard, None)).lower(

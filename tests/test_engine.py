@@ -216,6 +216,12 @@ plan = engine.make_plan(seed=29, num_streams=64, num_steps=16,
 ok["pallas_faithful"] = bool(np.array_equal(
     np.asarray(engine.generate(plan, backend="xla")),
     np.asarray(engine.generate_sharded(plan, backend="pallas"))))
+# several row tiles: each tile's start state is chained from the last
+plan = engine.make_plan(seed=29, num_streams=64, num_steps=40,
+                        mode="faithful")
+ok["pallas_faithful_tiles"] = bool(np.array_equal(
+    np.asarray(engine.generate(plan, backend="xla")),
+    np.asarray(engine.generate_sharded(plan, backend="pallas", block_t=8))))
 # sampler stage rides through the shard_map fan-out (uneven split, bf16)
 plan = engine.make_plan(seed=37, num_streams=26, num_steps=16,
                         sampler="uniform", out_dtype="bfloat16")
@@ -240,5 +246,5 @@ def test_generate_sharded_multi_device_subprocess():
     rep = json.loads(out.stdout.strip().splitlines()[-1])
     assert rep["devices"] == 4
     assert rep["ctr"] and rep["faithful"] and rep["uneven"]
-    assert rep["pallas_faithful"]
+    assert rep["pallas_faithful"] and rep["pallas_faithful_tiles"]
     assert rep["sampler"]

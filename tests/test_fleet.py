@@ -361,6 +361,22 @@ def test_adopt_refused_while_owner_lives(tmp_path):
 BURST, TENANTS, SEED = 64, 16, 0
 
 
+@pytest.mark.parametrize("platforms", [None, "", "tpu", "tpu,cpu"])
+def test_fleet_refuses_to_spawn_from_a_tpu_process(platforms, tmp_path,
+                                                   monkeypatch):
+    """A chip belongs to one process: shards that inherit a platform list
+    allowing a TPU would reach for one chip, so the fleet refuses."""
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    cfg = FleetConfig(num_shards=2, seed=SEED,
+                      journal_dir=str(tmp_path / "j"))
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        Fleet(cfg)
+    assert not (tmp_path / "j").exists()   # refused before any set-up
+
+
 def _fleet_digest(tmp_path, name, fault_plan, **client_kw):
     cfg = FleetConfig(num_shards=2, seed=SEED,
                       journal_dir=str(tmp_path / name))
