@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import engine, sampler as sampler_mod, stream as tstream, u64
+from repro.runtime import spans
 
 _M64 = (1 << 64) - 1
 
@@ -326,9 +327,10 @@ class BlockService:
         if name not in self._channels:
             raise KeyError(f"channel {name!r} is not open; "
                            f"have {sorted(self._channels)}")
-        with self._lock:
+        with spans.span("blocks.lease") as sp, self._lock:
             led = self._ledgers[name]
             lo = led.next if at is None else int(at)
+            sp.window = lo
             hi = lo + length
             if hi > _M64:
                 raise LeaseError(f"window [{lo}, {hi}) exceeds the u64 "
@@ -355,9 +357,10 @@ class BlockService:
         if name not in self._channels:
             raise KeyError(f"channel {name!r} is not open; "
                            f"have {sorted(self._channels)}")
-        with self._lock:
+        with spans.span("blocks.lease") as sp, self._lock:
             led = self._ledgers[name]
             lo0 = led.next if at is None else int(at)
+            sp.window = lo0
             if lo0 + n * length > _M64:
                 raise LeaseError(f"window [{lo0}, {lo0 + n * length}) "
                                  f"exceeds the u64 counter space")
@@ -376,7 +379,7 @@ class BlockService:
 
     def commit(self, lease: Lease) -> None:
         """Move a reserved window into the durable (checkpointable) ledger."""
-        with self._lock:
+        with spans.span("blocks.commit", lease.lo), self._lock:
             self._ledgers[lease.channel].commit(lease.lo, lease.hi)
 
     def release(self, lease) -> None:
@@ -571,19 +574,20 @@ class BlockService:
         the retired array is deleted (donated producer ring).
         """
         ch = self._channels[lease.channel]
-        if ch.window_fn is not None:
+        if ch.window_fn is not None and retired is not None:
+            raise ValueError(f"channel {lease.channel!r} has a custom "
+                             f"window_fn; donation needs a plan channel")
+        with spans.span("blocks.dispatch", lease.lo):
+            if ch.window_fn is not None:
+                return ch.window_fn(lease.lo, lease.hi)
+            s = ch.sampler if sampler is None else sampler
+            d = ch.out_dtype if out_dtype is None else out_dtype
+            fn = self._window_fn(ch, lease.length, s, d,
+                                 donate=retired is not None)
+            args = self._ctr_args(lease.lo)
             if retired is not None:
-                raise ValueError(f"channel {lease.channel!r} has a custom "
-                                 f"window_fn; donation needs a plan channel")
-            return ch.window_fn(lease.lo, lease.hi)
-        s = ch.sampler if sampler is None else sampler
-        d = ch.out_dtype if out_dtype is None else out_dtype
-        fn = self._window_fn(ch, lease.length, s, d,
-                             donate=retired is not None)
-        args = self._ctr_args(lease.lo)
-        if retired is not None:
-            return fn(*args, retired)
-        return fn(*args)
+                return fn(*args, retired)
+            return fn(*args)
 
     def generate_many(self, leases: List[Lease], *,
                       sampler: Optional[str] = None,
@@ -623,12 +627,13 @@ class BlockService:
                                  "not supported; use generate(lease, "
                                  "retired=...) for W=1")
             return self.generate(leases[0], sampler=s, out_dtype=d)[None]
-        fn = self._window_fn(ch, L, s, d, fuse=len(leases),
-                             donate=retired is not None)
-        args = self._ctr_args(leases[0].lo)
-        if retired is not None:
-            return fn(*args, retired)
-        return fn(*args)
+        with spans.span("blocks.dispatch", leases[0].lo):
+            fn = self._window_fn(ch, L, s, d, fuse=len(leases),
+                                 donate=retired is not None)
+            args = self._ctr_args(leases[0].lo)
+            if retired is not None:
+                return fn(*args, retired)
+            return fn(*args)
 
     def regenerate(self, name: str, lo: int, length: int, *,
                    sampler: Optional[str] = None,
@@ -802,12 +807,13 @@ class BlockProducer:
 
     def _put(self, item) -> bool:
         """queue.put with stop-polling; False once stop is requested."""
-        while not self._stop.is_set():
-            try:
-                self._queue.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
+        with spans.span("blocks.put", item[0].lo):
+            while not self._stop.is_set():
+                try:
+                    self._queue.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     def _work(self) -> None:
@@ -883,26 +889,34 @@ class BlockProducer:
         return self
 
     def __next__(self) -> Tuple[Lease, Any]:
-        while True:
-            if self._error is not None and self._queue.empty():
-                err, self._error = self._error, None
-                raise err
-            try:
-                item = self._queue.get(timeout=0.1)
-            except queue.Empty:
-                continue
-            if item is None:
-                if self._error is not None:
+        # the queue wait; its note says whether the consumer found the
+        # queue empty, i.e. waited on the producer
+        with spans.span("blocks.get") as sp:
+            if sp.recording and self._queue.empty():
+                sp.note = "empty"
+            while True:
+                if self._error is not None and self._queue.empty():
                     err, self._error = self._error, None
                     raise err
-                raise StopIteration
-            lease, block = item
-            self._service.commit(lease)
-            if self._donate and self._fuse == 1:
-                if self._held is not None:
-                    self._recycle.put(self._held)  # retire block k
-                self._held = block
-            return lease, block
+                try:
+                    item = self._queue.get(timeout=0.1)
+                    break
+                except queue.Empty:
+                    continue
+            if item is not None:
+                sp.window = item[0].lo
+        if item is None:
+            if self._error is not None:
+                err, self._error = self._error, None
+                raise err
+            raise StopIteration
+        lease, block = item
+        self._service.commit(lease)
+        if self._donate and self._fuse == 1:
+            if self._held is not None:
+                self._recycle.put(self._held)  # retire block k
+            self._held = block
+        return lease, block
 
     def close(self) -> None:
         """Stop the thread and release every unconsumed reservation."""

@@ -15,7 +15,7 @@ import pytest
 
 from repro.core import engine, sampler, stream
 from repro.quality import battery, cross, pit
-from repro.runtime import blocks
+from repro.runtime import blocks, spans
 from repro.service import audit, frontend, server, tenants
 
 #: the audited public surface: (symbol, minimum docstring length)
@@ -76,6 +76,9 @@ PUBLIC_SYMBOLS = [
     blocks.BlockService.producer,
     blocks.Lease,
     blocks.BlockProducer,
+    spans.span,
+    spans.recorded,
+    spans.clear,
     battery.run_battery,
     tenants.tenant_region,
     tenants.TenantRegistry,
@@ -104,7 +107,7 @@ EXAMPLE_BEARING = [
     sampler.parse, sampler.apply, sampler.result_dtype,
     sampler.poisson_thresholds, sampler.alias_table,
     pit.regularized_gamma_p, pit.discrete_cdf_table, pit.pit_words,
-    blocks.BlockService, blocks.Lease, blocks.BlockProducer,
+    blocks.BlockService, blocks.Lease, blocks.BlockProducer, spans.span,
     battery.run_battery,
     tenants.tenant_region, tenants.TenantRegistry,
     frontend.RandRequest, server.RandServer, audit.Journal, audit.replay,
@@ -130,7 +133,8 @@ def test_public_symbol_has_example(symbol):
 
 
 @pytest.mark.parametrize("module", [engine, sampler, stream, blocks,
-                                    tenants, frontend, server, audit, pit],
+                                    spans, tenants, frontend, server, audit,
+                                    pit],
                          ids=lambda m: m.__name__)
 def test_doctests_run_clean(module):
     results = doctest.testmod(module, verbose=False)
