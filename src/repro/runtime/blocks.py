@@ -50,6 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import engine, sampler as sampler_mod, stream as tstream, u64
 from repro.runtime import spans
@@ -493,7 +494,9 @@ class BlockService:
 
         The counter is TRACED (plan.offset=None), so every equal-length
         lease of a channel reuses one executable; traced and static
-        counters are bit-identical by the engine's parity tests.
+        counters are bit-identical by the engine's parity tests.  It
+        enters as two host ``uint32`` scalars from ``_ctr_args``, on
+        jit's C++ dispatch path.
 
         Variants (cache-keyed alongside the shape):
 
@@ -556,9 +559,20 @@ class BlockService:
         self._window_fns[key] = window
         return window
 
-    def _ctr_args(self, lo: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        c_hi, c_lo = (u64.to_u32(v) for v in u64.const64(lo))
-        return jnp.asarray(c_hi), jnp.asarray(c_lo)
+    def _ctr_args(self, lo: int) -> Tuple[np.uint32, np.uint32]:
+        """Counter ``lo`` as the window program's ``(hi, lo)`` arguments.
+
+        Host ``numpy.uint32`` scalars, not device arrays: jit takes them
+        on its C++ dispatch path and places them itself, so a window
+        costs no Python-level array construction and no extra device
+        program.  The avals (``u32[]``) and the compiled program are
+        those of device scalars, but jit caches the two calling
+        conventions as separate executables, so every plan-channel
+        dispatch (``generate``, ``generate_many``, ``regenerate``) takes
+        its counter from here and nowhere else.  ``bench/faults.py``
+        patches this method to plant its counter faults.
+        """
+        return u64.const64(lo)
 
     def generate(self, lease: Lease, *, sampler: Optional[str] = None,
                  out_dtype: Optional[str] = None,

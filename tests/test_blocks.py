@@ -163,6 +163,64 @@ def test_generate_sampler_override():
     assert np.array_equal(bits, ref)
 
 
+def _windows(kind, svc, name, length):
+    """(lease, block) pairs of three delivery paths, all around 2**32 so
+    the counter's high word changes inside or between windows."""
+    if kind == "generate":                  # carry inside the window
+        lease = svc.lease(name, length, at=2**32 - length // 2)
+        return [(lease, svc.generate(lease))]
+    if kind == "generate_many":             # carry between the windows
+        leases = svc.lease_many(name, length, 2, at=2**32 - length)
+        return list(zip(leases, svc.generate_many(leases)))
+    with svc.producer(name, length, count=4, start=2**32 - 2 * length) as p:
+        return [(lease, np.asarray(blk)) for lease, blk in p]
+
+
+@pytest.mark.parametrize("kind", ["generate", "generate_many", "producer"])
+def test_windows_across_the_counter_word_match_static_plan(kind):
+    svc = BlockService(seed=31)
+    svc.open("w", num_streams=8)
+    got = _windows(kind, svc, "w", 16)
+    assert got[0][0].lo < 2**32 < got[-1][0].hi
+    for lease, blk in got:
+        ref = np.asarray(engine.generate(lease.plan(), backend="ref"))
+        assert np.array_equal(np.asarray(blk), ref)
+
+
+def test_warm_window_dispatch_runs_no_eager_primitive(monkeypatch):
+    """A warm plan-channel window is one call of its jitted program: the
+    counter enters as host scalars, so nothing runs op by op (every
+    ``apply_primitive`` looks up ``xla_primitive_callable``), and three
+    windows, one past 2**32, reuse one executable."""
+    from jax._src import dispatch
+    svc = BlockService(seed=5)
+    svc.open("d", num_streams=8)
+    warm = svc.lease("d", 16)
+    svc.generate(warm).block_until_ready()
+    (fn,) = svc._window_fns.values()
+
+    calls = []
+    orig = dispatch.xla_primitive_callable
+
+    def counting(prim, **params):
+        calls.append(prim.name)
+        return orig(prim, **params)
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", counting)
+    leases = [svc.lease("d", 16), svc.lease("d", 16, at=2**32 + 16)]
+    blocks = [svc.generate(lease) for lease in [warm] + leases]
+    assert calls == []
+    monkeypatch.undo()
+    assert fn._cache_size() == 1
+    for lease, blk in zip([warm] + leases, blocks):
+        ref = np.asarray(engine.generate(lease.plan(), backend="ref"))
+        assert np.array_equal(np.asarray(blk), ref)
+    # host scalars and device scalars lower to one program
+    args = svc._ctr_args(leases[-1].lo)
+    assert [int(a) for a in args] == [1, 16]
+    assert (fn.lower(*args).as_text()
+            == fn.lower(*map(jnp.asarray, args)).as_text())
+
+
 def test_take_commits_and_equal_length_leases_share_one_executable():
     svc = BlockService(seed=9)
     svc.open("t", num_streams=4)
