@@ -541,9 +541,17 @@ class BlockService:
                     plan, fuse, backend=backend, block_t=block_t,
                     block_s=block_s)
             if mesh is not None:
-                return engine.generate_sharded(
-                    plan, mesh=mesh, axis_names=axes, backend=backend,
-                    block_t=block_t, block_s=block_s)
+                # The window leaves sharded by stream, each device
+                # holding its own columns, whatever the generation's
+                # last op: left to inference, a window built from one
+                # shard's columns comes out whole on every device.  (An
+                # S that does not divide over the mesh is replicated.)
+                return jax.lax.with_sharding_constraint(
+                    engine.generate_sharded(
+                        plan, mesh=mesh, axis_names=axes, backend=backend,
+                        block_t=block_t, block_s=block_s),
+                    jax.sharding.NamedSharding(
+                        mesh, jax.sharding.PartitionSpec(None, axes)))
             return engine.generate(plan, backend=backend, block_t=block_t,
                                    block_s=block_s)
 
