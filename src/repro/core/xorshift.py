@@ -219,29 +219,70 @@ def _matvec_traced(mat, s: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(bits << bitpos, axis=-1, dtype=U32)
 
 
+@functools.lru_cache(maxsize=None)
+def _pow2_bit_matrices() -> np.ndarray:
+    """``_packed_pow2_matrices(64)`` unpacked to 0/1 bits.
+
+    Shape (64, 128, 128) uint8: [k, row, col], entry = bit ``col`` of
+    packed row ``row`` of M**(2**k).
+    """
+    packed = _packed_pow2_matrices(64)
+    bits = (packed[..., None] >> np.arange(32, dtype=np.uint32)) & 1
+    return bits.reshape(64, STATE_BITS, STATE_BITS).astype(np.uint8)
+
+
+def _gf2_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Batched GF(2) product of 0/1 bf16 matrices, reduced mod 2.
+
+    Exact: every entry is 0 or 1 and every sum is at most 128, which
+    bf16 inputs and f32 accumulation hold without rounding.
+    """
+    prod = jnp.matmul(a, b, preferred_element_type=jnp.float32)
+    return (prod.astype(jnp.int32) & 1).astype(jnp.bfloat16)
+
+
+def _jump_matrix_traced(n_hi: jnp.ndarray, n_lo: jnp.ndarray) -> jnp.ndarray:
+    """M**n for a dynamic 64-bit count, as a (128, 128) 0/1 bf16 matrix.
+
+    Selects M**(2**k) where bit k of n is set and the identity where it
+    is clear, then multiplies the 64 selections in a pairwise tree (six
+    batched 128x128 products).  The factors are powers of M, so they
+    commute and the order does not matter.  No state dimension.
+    """
+    shifts = jnp.arange(32, dtype=U32)
+    bits = jnp.concatenate([(jnp.asarray(n_lo, U32) >> shifts) & U32(1),
+                            (jnp.asarray(n_hi, U32) >> shifts) & U32(1)])
+    pows = jnp.asarray(_pow2_bit_matrices(), jnp.bfloat16)  # (64, 128, 128)
+    eye = jnp.eye(STATE_BITS, dtype=jnp.bfloat16)
+    mats = jnp.where((bits == 1)[:, None, None], pows, eye)
+    while mats.shape[0] > 1:
+        pairs = mats.reshape(-1, 2, STATE_BITS, STATE_BITS)
+        mats = _gf2_matmul(pairs[:, 0], pairs[:, 1])
+    return mats[0]
+
+
+def _pack_rows(bits: jnp.ndarray) -> jnp.ndarray:
+    """(128, 128) 0/1 matrix -> (128, 4) uint32 packed rows."""
+    words = bits.astype(U32).reshape(STATE_BITS, STATE_WORDS, 32)
+    return jnp.sum(words << jnp.arange(32, dtype=U32), axis=-1, dtype=U32)
+
+
 def jump_traced(state: jnp.ndarray, n_hi: jnp.ndarray, n_lo: jnp.ndarray
                 ) -> jnp.ndarray:
     """Traced jump-ahead by a dynamic 64-bit count (n_hi, n_lo).
 
-    ``state``: (..., 4) uint32.  Cost: 64 conditional 128x128 GF(2) matvecs,
-    each a (128, 4) & (..., 1, 4) popcount-parity — used once per bulk call,
-    never per element.
+    ``state``: (..., 4) uint32.  Cost: one composed 128x128 GF(2) jump
+    matrix M**n (``_jump_matrix_traced``, independent of the table's
+    size) and one packed popcount-parity matvec over the whole table —
+    used once per bulk call, never per element.
     """
-    mats = jnp.asarray(_packed_pow2_matrices(64))  # (64, 128, 4)
-
-    def body(k, s):
-        bit = jnp.where(k < 32, (n_lo >> k.astype(U32)) & U32(1),
-                        (n_hi >> (k.astype(U32) - U32(32))) & U32(1))
-        jumped = _matvec_traced(mats[k], s)
-        return jnp.where((bit == 1)[..., None] if bit.ndim else bit == 1,
-                         jumped, s)
-
-    return jax.lax.fori_loop(0, 64, body, state)
+    return _matvec_traced(_pack_rows(_jump_matrix_traced(n_hi, n_lo)), state)
 
 
 def jump_static(state: jnp.ndarray, n: int) -> jnp.ndarray:
     """In-graph jump of a (..., 4) state table by a static count ``n``:
-    one matvec per set bit of ``n`` (``jump_traced`` does 64)."""
+    one matvec per set bit of ``n`` (``jump_traced`` does one, after
+    composing M**n)."""
     mats = _packed_pow2_matrices(64)
     n = int(n)
     k = 0

@@ -1,10 +1,13 @@
 """xorshift128 decorrelator: step, GF(2) jump-ahead, substream spacing."""
+import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import golden, xorshift
+from repro.core import golden, u64, xorshift
 
 words = st.tuples(*[st.integers(min_value=0, max_value=(1 << 32) - 1)] * 4)
 
@@ -54,15 +57,83 @@ def test_lane_table_matches_substream_state():
             xorshift.DEFAULT_SEED, i)
 
 
-def test_jump_traced_matches_host(rng):
-    states = rng.integers(0, 1 << 32, (8, 4), dtype=np.uint32)
-    for n in [0, 1, 5, 1000, (1 << 33) + 7]:
-        jumped = np.asarray(xorshift.jump_traced(
-            jnp.asarray(states),
-            jnp.uint32(n >> 32), jnp.uint32(n & 0xFFFFFFFF)))
-        for i in range(8):
-            exp = xorshift.jump(tuple(int(w) for w in states[i]), n)
-            assert tuple(int(w) for w in jumped[i]) == exp, (i, n)
+_M64 = (1 << 64) - 1
+# Window multiples, the extremes of the 64-bit count, and a seeded random one.
+TRACED_COUNTS = [0, 1, 5, 1000, (1 << 33) + 7, 1 << 63, _M64, 4096, 37 * 4096,
+                 4096 * ((1 << 40) + 3),
+                 int(np.random.default_rng(20261019).integers(0, 1 << 63)) * 2 + 1]
+TILE_STRIDE = 256  # _faithful_tile_states' block_t
+
+
+def _split(n):
+    return jnp.uint32(n >> 32), jnp.uint32(n & 0xFFFFFFFF)
+
+
+def _host_jumped(states, n):
+    return [xorshift.jump(tuple(int(w) for w in s), n) for s in states]
+
+
+@pytest.mark.parametrize("shape", ["(8, 4)", "(1, 4)", "vmap16"])
+@pytest.mark.parametrize("n", TRACED_COUNTS)
+def test_jump_traced_matches_host(rng, n, shape):
+    if shape == "vmap16":
+        # As _faithful_tile_states uses it: one table, 16 tile offsets.
+        states = rng.integers(0, 1 << 32, (4, 4), dtype=np.uint32)
+        tiles = jnp.arange(16, dtype=jnp.uint32)
+
+        def tile(i):
+            off = u64.add64(_split(n), u64.mul32_wide(i, jnp.uint32(TILE_STRIDE)))
+            return xorshift.jump_traced(jnp.asarray(states), off[0], off[1])
+
+        jumped = np.asarray(jax.vmap(tile)(tiles))
+        for i in range(16):
+            exp = _host_jumped(states, (n + i * TILE_STRIDE) & _M64)
+            assert [tuple(int(w) for w in r) for r in jumped[i]] == exp, (i, n)
+        return
+    rows = int(shape[1])
+    states = rng.integers(0, 1 << 32, (rows, 4), dtype=np.uint32)
+    jumped = np.asarray(xorshift.jump_traced(jnp.asarray(states), *_split(n)))
+    assert [tuple(int(w) for w in r) for r in jumped] == _host_jumped(states, n)
+
+
+@pytest.mark.parametrize("n", [0, 1, 37 * 4096, _M64, TRACED_COUNTS[-1]])
+def test_jump_matrix_matches_host_power(n):
+    """The composed 128x128 matrix alone equals the host's M**n, whose
+    column j is the host jump of basis state j."""
+    host = np.zeros((xorshift.STATE_BITS, xorshift.STATE_BITS), np.uint8)
+    for j in range(xorshift.STATE_BITS):
+        col = xorshift._state_to_int(
+            xorshift.jump(xorshift._int_to_state(1 << j), n))
+        host[:, j] = [(col >> r) & 1 for r in range(xorshift.STATE_BITS)]
+    composed = np.asarray(xorshift._jump_matrix_traced(*_split(n)))
+    assert np.array_equal(composed.astype(np.uint8), host)
+
+
+def _all_eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _all_eqns(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _all_eqns(sub)
+
+
+def test_jump_traced_one_table_wide_matvec():
+    """One popcount over the table's rows and no loop carrying them: the
+    jump composes M**n first, instead of 64 table-wide matvecs."""
+    rows = 1024
+    jaxpr = jax.make_jaxpr(jax.jit(xorshift.jump_traced))(
+        jnp.zeros((rows, 4), jnp.uint32), *_split(12345))
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    wide = [e for e in eqns if e.primitive.name == "population_count"
+            and rows in e.invars[0].aval.shape]
+    assert len(wide) == 1, [e.primitive.name for e in eqns]
+    # A fori_loop with static bounds traces to scan, a dynamic one to while.
+    loops = [e for e in eqns if e.primitive.name in ("while", "scan")
+             and any(rows in v.aval.shape for v in e.outvars)]
+    assert not loops
 
 
 def test_xorshift_seq_golden_consistency():
