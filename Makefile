@@ -1,7 +1,7 @@
 PY ?= python
 export PYTHONPATH := src
 
-.PHONY: test test-fast bench bench-smoke install-dev service service-smoke fleet fleet-smoke roofline roofline-full inference inference-smoke
+.PHONY: test test-fast install-dev service fleet inference
 
 install-dev:
 	$(PY) -m pip install -e ".[test]"
@@ -12,25 +12,13 @@ test:              ## tier-1 suite
 test-fast:         ## tier-1 minus the slow end-to-end tests
 	$(PY) -m pytest -x -q -m "not slow"
 
-bench:             ## full benchmark battery (CSV to stdout)
-	$(PY) -m benchmarks.run
-
-bench-smoke:       ## CI-sized throughput + sampler smoke (parity, timing, BENCH_throughput.json)
-	$(PY) -m benchmarks.throughput
-
 service:           ## RandService: 1024-tenant burst + replay check, then serve until SIGINT (graceful drain)
 	$(PY) -m repro.service --burst 1024 --tenants 1024 --verify-replay --linger 600
-
-service-smoke:     ## RandService burst bench rows only (service/* in BENCH_throughput.json)
-	$(PY) -m benchmarks.throughput service
 
 fleet:             ## 2-shard wire fleet (pipelined binary clients, coalescing+pools on): kill-mid-burst failover, digest vs no-fault, union replay
 	rm -rf /tmp/repro-fleet
 	JAX_PLATFORMS=cpu $(PY) -m repro.service --fleet 2 --burst 256 --tenants 64 \
 	    --journal-dir /tmp/repro-fleet --fault-plan kill@128 --verify-replay
-
-fleet-smoke:       ## fleet bench rows (binary/json pair, hammer/unique/kill; fleet/* in BENCH_throughput.json)
-	JAX_PLATFORMS=cpu $(PY) -m benchmarks.throughput fleet
 
 inference:         ## continuous batcher: fused/xla parity run, then kill-mid-run + journal replay, digest vs no-fault
 	rm -rf /tmp/repro-inference && mkdir -p /tmp/repro-inference
@@ -43,12 +31,3 @@ inference:         ## continuous batcher: fused/xla parity run, then kill-mid-ru
 	    --digest-out /tmp/repro-inference/replay.digest
 	cmp /tmp/repro-inference/base.digest /tmp/repro-inference/replay.digest
 	@echo "inference: kill-mid-run replay digest == no-fault digest"
-
-inference-smoke:   ## inference bench rows (offline parity run + step micro; inference/* in BENCH_throughput.json)
-	$(PY) -m benchmarks.throughput inference
-
-roofline:          ## roofline smoke + regression gate (merges roofline/* rows, fails if fused/donated regress)
-	$(PY) -m benchmarks.roofline --check
-
-roofline-full:     ## full roofline sweep (S=T=2048, all sampler classes) + gate
-	$(PY) -m benchmarks.roofline --full --check

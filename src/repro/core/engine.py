@@ -38,12 +38,11 @@ from the replicated root state with ZERO cross-device communication,
 exactly as adding SOU instances on the FPGA costs no extra root-generator
 hardware.
 
-This module is the single home of the shared plumbing that used to be
-re-implemented by ``core/stream.py``, ``kernels/ops.py`` and the
-benchmarks: family/leaf-offset derivation (``family_from_seed``,
-``derive_leaf``, ``leaf_table``), root-state/counter-row expansion
-(``root_and_ctr_rows``) and the xorshift128 start-state prep for the
-faithful decorrelator.
+This module is the single home of the shared plumbing of
+``core/stream.py`` and ``kernels/ops.py``: family/leaf-offset
+derivation (``family_from_seed``, ``derive_leaf``, ``leaf_table``),
+root-state/counter-row expansion (``root_and_ctr_rows``) and the
+xorshift128 start-state prep for the faithful decorrelator.
 
 Import layering: ``engine`` sits in ``repro.core`` and imports only the
 arithmetic cores (lcg/splitmix/u64/xorshift); the kernel modules are
@@ -227,71 +226,19 @@ def _faithful_tile_states(plan: GenPlan, block_t: int, n_tiles: int,
                           xs0: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """(n_tiles, 4, S) per-(row-tile, stream) xorshift start states.
 
-    When ``xs0`` is given — (S, 4) states already advanced to plan.ctr,
-    carrying GLOBAL substream identity (the sharded case) — tile states
-    are chained from it in-graph, each one static ``block_t`` jump past
-    the last, instead of rebuilding the lane table from local indices.
+    Tile ``i`` starts ``i * block_t`` steps past ``plan.ctr``.  The
+    states are chained in-graph from the (S, 4) states at ``plan.ctr``,
+    each one static ``block_t`` jump past the last.  ``xs0`` supplies
+    those states where substream identity follows the GLOBAL stream
+    index (the sharded case); otherwise they are
+    ``_faithful_start_states(plan)``.
     """
-    S = plan.num_streams
-    if xs0 is not None:
-        states = [xs0]
-        for _ in range(n_tiles - 1):
-            states.append(xorshift.jump_static(states[-1], block_t))
-        return jnp.transpose(jnp.stack(states), (0, 2, 1))  # (n_tiles, 4, S)
-    if plan.offset is not None:
-        # Vectorized GF(2) jumps over the WHOLE lane table: n_tiles batched
-        # matvecs instead of an O(S * n_tiles) python-int jump loop
-        # (minutes of host work at S = 2**14).
-        tbl = xorshift.lane_table(S)
-        if plan.offset:
-            tbl = xorshift.jump_batch(tbl, plan.offset)
-        states = np.empty((n_tiles, 4, S), np.uint32)
-        for i in range(n_tiles):
-            states[i] = tbl.T
-            if i + 1 < n_tiles:
-                tbl = xorshift.jump_batch(tbl, block_t)
-        return jnp.asarray(states)
-    tbl = jnp.asarray(xorshift.lane_table(S))  # (S, 4)
-
-    def tile(i):
-        off = u64.add64(plan.ctr, u64.mul32_wide(i, U32(block_t)))
-        return xorshift.jump_traced(tbl, off[0], off[1])  # (S, 4)
-
-    states = jax.vmap(tile)(jnp.arange(n_tiles, dtype=U32))
-    return jnp.transpose(states, (0, 2, 1))  # (n_tiles, 4, S)
-
-
-def _faithful_states_at(plan: GenPlan, offsets) -> jnp.ndarray:
-    """(K, 4, S) xorshift start states at explicit per-tile offsets.
-
-    ``offsets`` is a non-decreasing list of static python ints, relative
-    to ``plan.ctr`` — the generalization of ``_faithful_tile_states``'s
-    uniform ``i * block_t`` stride that multi-window tiling needs (tile
-    (w, i) sits at ``w * window_len + i * bt``, which is monotone but
-    not uniform when the window length is not a tile multiple).
-    """
-    S = plan.num_streams
-    if plan.offset is not None:
-        tbl = xorshift.lane_table(S)
-        if plan.offset:
-            tbl = xorshift.jump_batch(tbl, plan.offset)
-        states = np.empty((len(offsets), 4, S), np.uint32)
-        at = 0
-        for i, off in enumerate(offsets):
-            if off != at:
-                tbl = xorshift.jump_batch(tbl, off - at)
-                at = off
-            states[i] = tbl.T
-        return jnp.asarray(states)
-    tbl = jnp.asarray(xorshift.lane_table(S))  # (S, 4)
-    offs = np.array([u64.split64(o) for o in offsets], np.uint32)
-
-    def tile(off_hi, off_lo):
-        nh, nl = u64.add64(plan.ctr, (off_hi, off_lo))
-        return xorshift.jump_traced(tbl, nh, nl)  # (S, 4)
-
-    states = jax.vmap(tile)(jnp.asarray(offs[:, 0]), jnp.asarray(offs[:, 1]))
-    return jnp.transpose(states, (0, 2, 1))  # (K, 4, S)
+    if xs0 is None:
+        xs0 = _faithful_start_states(plan)
+    states = [xs0]
+    for _ in range(n_tiles - 1):
+        states.append(xorshift.jump_static(states[-1], block_t))
+    return jnp.transpose(jnp.stack(states), (0, 2, 1))  # (n_tiles, 4, S)
 
 
 def _leaf_permuted(roots: U64Pair, h: U64Pair) -> jnp.ndarray:
@@ -521,19 +468,19 @@ def generate_windows(plan: GenPlan, num_windows: int, *,
 
     Window ``w`` covers counter steps ``[ctr + w*T, ctr + (w+1)*T)`` —
     bit-identical on every backend to stacking W ``generate`` calls on
-    ``shift_plan(plan, w*T)``, but dispatched as ONE device program:
+    ``shift_plan(plan, w*T)``, but dispatched as ONE device program.
+    Counter addressing makes W consecutive windows one wide window: row
+    ``w*T + t`` of a ``W*T``-row block is step ``t`` of window ``w``, so
+    every backend but ``"ref"`` generates the wide block and reshapes
+    it.  A tile may straddle a window edge: the ctr kernel is a pure map
+    of (row counter, stream), the faithful kernel restarts its chain per
+    tile from a state jumped to that tile's own offset, and the sampler
+    stages pair rows ``(2k, 2k+1)`` only, with an even T for ``normal``.
+    ``"ref"`` is literally the stacked loop (the oracle).
 
-      * ``"ref"``     literally the stacked loop (the oracle),
-      * ``"xla"``     one fused (W*T, S) generation reshaped to windows
-                      (counter addressing makes consecutive windows one
-                      contiguous block),
-      * ``"pallas"``  one ``pallas_call`` whose grid grows a leading
-                      window axis — W windows cost one kernel launch
-                      (``thundering_block.block_ctr_windows``).
-
-    This is the dispatch-amortization lever of the roofline chase: a
-    standing producer that fuses W windows per call pays the per-call
-    jit/launch overhead once per W blocks (``BlockProducer(fuse=W)``).
+    This is the dispatch-amortization lever: a standing producer that
+    fuses W windows per call pays the per-call jit/launch overhead once
+    per W blocks (``BlockProducer(fuse=W)``).
 
     Example:
         >>> import numpy as np
@@ -559,30 +506,9 @@ def generate_windows(plan: GenPlan, num_windows: int, *,
         return jnp.stack([generate(shift_plan(plan, w * T), backend="ref",
                                    block_t=block_t, block_s=block_s)
                           for w in range(W)])
-    if name == "xla":
-        wide = dataclasses.replace(plan, num_steps=W * T)
-        out = generate(wide, backend="xla", block_t=block_t,
-                       block_s=block_s)
-        return out.reshape(W, T, S)
-    from repro.kernels import thundering_block as _tb
-    spec = sampler_mod.parse(plan.sampler)
-    roots, ctr_rows = root_and_ctr_rows(plan.x0, plan.ctr, W * T)
-    if plan.mode == "ctr":
-        return _tb.block_ctr_windows(
-            roots, ctr_rows, plan.h, num_windows=W, window_len=T,
-            block_t=block_t, block_s=block_s, interpret=use_interpret(),
-            deco=plan.deco, sampler=spec, out_dtype=plan.out_dtype)
-    if plan.mode == "faithful":
-        bt = _tb.tile_t(block_t, T,
-                        sampler_mod.result_dtype(spec, plan.out_dtype))
-        n_t = -(-_pad_to(T, bt) // bt)
-        states = _faithful_states_at(
-            plan, [w * T + i * bt for w in range(W) for i in range(n_t)])
-        return _tb.block_faithful_windows(
-            roots, plan.h, states, num_windows=W, window_len=T,
-            block_t=bt, block_s=block_s, interpret=use_interpret(),
-            sampler=spec, out_dtype=plan.out_dtype)
-    raise ValueError(f"unknown mode {plan.mode!r}")
+    wide = dataclasses.replace(plan, num_steps=W * T)
+    out = generate(wide, backend=name, block_t=block_t, block_s=block_s)
+    return out.reshape(W, T, S)
 
 
 def generate_flat(plan: GenPlan, *, backend: Optional[str] = None,

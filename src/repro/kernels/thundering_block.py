@@ -155,109 +155,6 @@ def block_ctr(roots, ctr_rows, h, *, block_t=DEFAULT_BLOCK_T,
     return out[:T, :S]
 
 
-def block_ctr_windows(roots, ctr_rows, h, *, num_windows: int,
-                      window_len: int, block_t=DEFAULT_BLOCK_T,
-                      block_s=DEFAULT_BLOCK_S, interpret=False,
-                      deco: str = "splitmix64", sampler=BITS,
-                      out_dtype: str = "float32") -> jnp.ndarray:
-    """(W, T, S) stack of W consecutive counter windows, ONE pallas_call.
-
-    The fusion behind ``engine.generate_windows``: instead of W separate
-    kernel dispatches (one per window — W trips through the launch path,
-    W small output allocations), the grid grows a leading *window* axis
-    ``(W, T_tiles, S_tiles)`` and the per-row root/counter streams are
-    indexed by the window ``program_id`` through the BlockSpec index
-    maps.  The kernel body is exactly ``_ctr_kernel`` — each (w, i, j)
-    program sees the same (BT, 1) root/counter columns it would have
-    seen as tile (i, j) of a standalone window-w call, so the output is
-    bit-identical to W stacked ``block_ctr`` calls by construction.
-
-    roots / ctr_rows: ((W*T,), (W*T,)) u32 — absolute per-row values for
-    all W windows, window-major (row w*T + t is step t of window w).
-    """
-    W, T = num_windows, window_len
-    S = h[0].shape[0]
-    assert roots[0].shape[0] == W * T, (roots[0].shape, W, T)
-    dtype = sampler_mod.result_dtype(sampler, out_dtype)
-    bt = tile_t(block_t, T, dtype)
-    bs = min(block_s, _pad_to(S, 128))
-    Tp, Sp = _pad_to(T, bt), _pad_to(S, bs)
-    n_t = Tp // bt
-
-    def pad_col(v):  # (W*T,) -> (W*Tp, 1): per-window tail padding
-        return jnp.pad(v.reshape(W, T), ((0, 0), (0, Tp - T))) \
-                  .reshape(W * Tp, 1)
-
-    def pad_row(v):  # (S,) -> (1, Sp)
-        return jnp.pad(v, (0, Sp - S)).reshape(1, Sp)
-
-    col = pl.BlockSpec((bt, 1), lambda w, i, j: (w * n_t + i, 0))
-    lane = pl.BlockSpec((1, bs), lambda w, i, j: (0, j))
-    out = pl.pallas_call(
-        functools.partial(_ctr_kernel, deco=deco, sampler=sampler,
-                          out_dtype=out_dtype),
-        grid=(W, n_t, Sp // bs),
-        in_specs=[col, col, col, col, lane, lane],
-        out_specs=pl.BlockSpec((bt, bs), lambda w, i, j: (w * n_t + i, j)),
-        out_shape=jax.ShapeDtypeStruct((W * Tp, Sp), dtype),
-        interpret=interpret,
-    )(pad_col(roots[0]), pad_col(roots[1]),
-      pad_col(ctr_rows[0]), pad_col(ctr_rows[1]),
-      pad_row(h[0]), pad_row(h[1]))
-    return out.reshape(W, Tp, Sp)[:, :T, :S]
-
-
-def block_faithful_windows(roots, h, xs_tile_states, *, num_windows: int,
-                           window_len: int, block_t=DEFAULT_BLOCK_T,
-                           block_s=DEFAULT_BLOCK_S, interpret=False,
-                           sampler=BITS, out_dtype: str = "float32"
-                           ) -> jnp.ndarray:
-    """(W, T, S) faithful-mode analogue of ``block_ctr_windows``.
-
-    xs_tile_states: (W * T_tiles, 4, S) uint32 — the xorshift128 state of
-    every stream at the first row of tile (w, i), pre-jumped to the
-    absolute offset ``w * T + i * bt`` (window-major flat order).  One
-    pallas_call over the (W, T_tiles, S_tiles) grid; the serial
-    decorrelator chain restarts per tile from its pre-jumped state
-    exactly as in ``block_faithful``.
-    """
-    W, T = num_windows, window_len
-    S = h[0].shape[0]
-    assert roots[0].shape[0] == W * T, (roots[0].shape, W, T)
-    dtype = sampler_mod.result_dtype(sampler, out_dtype)
-    bt = tile_t(block_t, T, dtype)
-    bs = min(block_s, _pad_to(S, 128))
-    Tp, Sp = _pad_to(T, bt), _pad_to(S, bs)
-    n_t = Tp // bt
-    assert xs_tile_states.shape == (W * n_t, 4, S), xs_tile_states.shape
-    xs = jnp.pad(xs_tile_states, ((0, 0), (0, 0), (0, Sp - S)))
-
-    def pad_col(v):
-        return jnp.pad(v.reshape(W, T), ((0, 0), (0, Tp - T))) \
-                  .reshape(W * Tp, 1)
-
-    def pad_row(v):
-        return jnp.pad(v, (0, Sp - S)).reshape(1, Sp)
-
-    col = pl.BlockSpec((bt, 1), lambda w, i, j: (w * n_t + i, 0))
-    lane = pl.BlockSpec((1, bs), lambda w, i, j: (0, j))
-    scratch = [] if sampler == BITS else [pltpu.VMEM((bt, bs), jnp.uint32)]
-    out = pl.pallas_call(
-        functools.partial(_faithful_kernel, block_t=bt, sampler=sampler,
-                          out_dtype=out_dtype),
-        grid=(W, n_t, Sp // bs),
-        in_specs=[col, col, lane, lane,
-                  pl.BlockSpec((1, 4, bs), lambda w, i, j: (w * n_t + i,
-                                                            0, j))],
-        out_specs=pl.BlockSpec((bt, bs), lambda w, i, j: (w * n_t + i, j)),
-        out_shape=jax.ShapeDtypeStruct((W * Tp, Sp), dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(pad_col(roots[0]), pad_col(roots[1]), pad_row(h[0]), pad_row(h[1]),
-      xs)
-    return out.reshape(W, Tp, Sp)[:, :T, :S]
-
-
 def block_faithful(roots, h, xs_tile_states, *, block_t=DEFAULT_BLOCK_T,
                    block_s=DEFAULT_BLOCK_S, interpret=False, sampler=BITS,
                    out_dtype: str = "float32") -> jnp.ndarray:

@@ -728,7 +728,7 @@ class BlockProducer:
     COMMITS its lease (consumed randomness enters the durable ledger at
     handoff, so a ledger snapshot between iterations is exact).
 
-    Two roofline levers ride on top of the base pipeline:
+    Two delivery levers ride on top of the base pipeline:
 
       * ``donate=True`` — the allocation-free steady state.  The
         producer pre-allocates a ring of ``depth + 2`` buffers (queue
@@ -743,12 +743,12 @@ class BlockProducer:
         out with ``np.array`` if you need it longer).
       * ``fuse=W`` — W windows per dispatch.  The thread leases W
         contiguous windows atomically (``lease_many``), generates their
-        ``(W, L, S)`` stack with ONE fused ``generate_windows`` call,
-        and enqueues per-window slices; commit stays per-block at
-        handoff.  With ``donate=True`` the stacks alternate through a
-        producer-local two-buffer ring (the enqueued slices are fresh
-        arrays, so the consumer never touches ring memory and no
-        validity window applies).
+        ``(W, L, S)`` stack with ONE fused ``generate_windows`` call
+        (one wide window of W*L steps), and enqueues per-window slices;
+        commit stays per-block at handoff.  With ``donate=True`` the
+        stacks alternate through a producer-local two-buffer ring (the
+        enqueued slices are fresh arrays, so the consumer never touches
+        ring memory and no validity window applies).
 
     Example:
         >>> from repro.runtime.blocks import BlockService

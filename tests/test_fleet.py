@@ -413,6 +413,16 @@ def test_fleet_kill_midburst_digest_equality(tmp_path):
     assert audit.response_digest(replayed) == baseline
 
 
+def test_fleet_json_wire_serves_the_binary_bytes(tmp_path):
+    """Wire v1 (JSON) and v2 (binary) are payload-transparent end to
+    end: the same burst over a fleet digests the same either way."""
+    binary, _, _ = _fleet_digest(tmp_path, "binary", FaultPlan())
+    json_v1, stats, _ = _fleet_digest(tmp_path, "json", FaultPlan(),
+                                      binary=False)
+    assert json_v1 == binary
+    assert stats["failovers"] == 0
+
+
 @pytest.mark.slow
 def test_fleet_hang_is_fenced_then_adopted(tmp_path):
     """A hung (alive but wedged) shard: adoption is refused while the

@@ -1,11 +1,8 @@
 """What keys on the platform or the environment outside the program:
-where the compile cache goes, and the roofline's bandwidth source."""
-import types
-
+where the compile cache goes."""
 import jax
 import pytest
 
-from benchmarks import roofline
 from repro import compile_cache
 
 
@@ -30,19 +27,3 @@ def test_compile_cache_directory(monkeypatch, tmp_path, from_env):
     finally:
         for k, v in saved.items():
             jax.config.update(k, v)
-
-
-@pytest.mark.parametrize("platform,kind,source", [
-    ("tpu", "TPU v5 lite", "table:TPU v5 lite"),
-    ("cpu", "cpu", "measured:cpu"),
-    ("tpu", "TPU v99", None),
-])
-def test_roofline_bandwidth_source(monkeypatch, platform, kind, source):
-    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
-    monkeypatch.setattr(roofline.jax, "devices", lambda *a: [dev])
-    monkeypatch.setattr(roofline, "_measured_bandwidth", lambda: 1.0)
-    if source is None:     # a TPU the table lacks is an error, not a guess
-        with pytest.raises(ValueError, match="KNOWN_BW"):
-            roofline.detect_bandwidth()
-    else:
-        assert roofline.detect_bandwidth()[1] == source

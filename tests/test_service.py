@@ -272,18 +272,6 @@ def test_journal_newline_less_tail_survives_reopen(tmp_path):
     assert [w["lo"] for w in j3.windows()] == [0, 8]
 
 
-def test_bench_json_filtered_merge(tmp_path):
-    from benchmarks.throughput import write_bench_json
-    path = tmp_path / "bench.json"
-    write_bench_json([{"name": "a", "variant": "x", "v": 1},
-                      {"name": "b", "variant": "x", "v": 2}], path)
-    write_bench_json([{"name": "b", "variant": "x", "v": 9}], path,
-                     merge=True)
-    import json
-    rows = json.loads(path.read_text())["rows"]
-    assert {(r["name"], r["v"]) for r in rows} == {("a", 1), ("b", 9)}
-
-
 def test_server_honors_caller_supplied_empty_registry():
     """An empty registry is falsy (__len__) — the server must keep the
     caller's instance anyway, or quotas silently stop applying."""

@@ -4,13 +4,15 @@ Each test lowers one kernel wrapper at real width with ``interpret=False``
 and compiles it for a described (not attached) ``v5e:2x2`` topology, so
 the TPU compiler's tiling and memory checks run here without a chip.
 ``engine.generate`` would take its CPU branch here, so the wrappers are
-called directly.  Every compile must carry a ``tpu_custom_call`` (the
-kernel really lowered through Mosaic).
+called directly; the window-stack test, which has no wrapper of its own,
+patches ``engine.use_interpret`` instead.  Every compile must carry a
+``tpu_custom_call`` (the kernel really lowered through Mosaic).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
+import dataclasses
 import functools
 import os
 
@@ -20,7 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import sampler
+from repro.core import engine, sampler
 from repro.inference.kernels import gumbel_argmax
 from repro.kernels import fused_dropout, mc, thundering_block as tb
 
@@ -93,15 +95,20 @@ def test_block_faithful_compiles(one_chip, spec):
     _assert_kernel(_compile_text(fn, one_chip, col, col, row, row, xs))
 
 
-def test_block_ctr_windows_compiles(one_chip):
+@pytest.mark.parametrize("mode", ["ctr", "faithful"])
+def test_generate_windows_compiles(one_chip, monkeypatch, mode):
+    """A fused producer's window stack, W windows as one wide window,
+    with the counter traced as the producer passes it."""
     W = 4
+    plan = engine.make_plan(seed=1, num_streams=S_BULK, num_steps=T_BULK,
+                            mode=mode)
+    monkeypatch.setattr(engine, "use_interpret", lambda: False)
 
-    def fn(r0, r1, c0, c1, h0, h1):
-        return tb.block_ctr_windows((r0, r1), (c0, c1), (h0, h1),
-                                    num_windows=W, window_len=T_BULK,
-                                    interpret=False)
-    col, row = ((W * T_BULK,), U32), ((S_BULK,), U32)
-    _assert_kernel(_compile_text(fn, one_chip, col, col, col, col, row, row))
+    def fn(hi, lo):
+        p = dataclasses.replace(plan, ctr=(hi, lo), offset=None)
+        return engine.generate_windows(p, W, backend="pallas")
+    scalar = ((), U32)
+    _assert_kernel(_compile_text(fn, one_chip, scalar, scalar))
 
 
 def test_fused_argmax_compiles(one_chip):

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import engine
+from repro.core import engine, xorshift
 
 BACKENDS = ("ref", "xla", "pallas")
 
@@ -83,20 +83,54 @@ def test_windows_from_nonzero_counter():
         assert np.array_equal(got, _stacked(plan, W))
 
 
-def test_windows_traced_counter_matches_static():
-    """The producer path: counter traced through jit, offset=None."""
-    T, S, W = 8, 16, 3
-    plan = engine.make_plan(seed=3, num_streams=S, num_steps=T)
+@pytest.mark.parametrize("mode", ["ctr", "faithful"])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_windows_traced_counter_matches_static(backend, mode):
+    """The producer path: counter traced through jit, offset=None.  The
+    counter sits just below 2**32, so the stack crosses the low word's
+    carry, and 8-row tiles straddle the 12-step window edges."""
+    T, S, W = 12, 16, 3
+    plan = engine.make_plan(seed=3, num_streams=S, num_steps=T, mode=mode,
+                            offset=2**32 - 20)
     traced = dataclasses.replace(plan, offset=None)
 
     @jax.jit
     def fn(hi, lo):
         p = dataclasses.replace(traced, ctr=(hi, lo))
-        return engine.generate_windows(p, W, backend="xla")
+        return engine.generate_windows(p, W, backend=backend, block_t=8,
+                                       block_s=16)
 
     hi, lo = plan.ctr
     got = np.asarray(fn(jnp.asarray(hi), jnp.asarray(lo)))
     assert np.array_equal(got, _stacked(plan, W))
+
+
+def test_faithful_tile_states_one_builder():
+    """Tile states of a wide window stack: a static offset, the same
+    counter traced, and a chain from the plan's own start states all
+    equal the host's per-tile jumps (tile i at offset + i * block_t)."""
+    T, S, W, bt = 12, 10, 3, 8        # 36 rows: 5 tiles, the last short
+    offset = 2**32 + 5
+    wide = engine.make_plan(seed=8, num_streams=S, num_steps=W * T,
+                            mode="faithful", offset=offset)
+    n_tiles = -(-W * T // bt)
+    static = np.asarray(engine._faithful_tile_states(wide, bt, n_tiles))
+
+    @jax.jit
+    def traced(hi, lo):
+        p = dataclasses.replace(wide, ctr=(hi, lo), offset=None)
+        return engine._faithful_tile_states(p, bt, n_tiles)
+
+    hi, lo = wide.ctr
+    from_ctr = np.asarray(traced(jnp.asarray(hi), jnp.asarray(lo)))
+    chained = np.asarray(engine._faithful_tile_states(
+        wide, bt, n_tiles, xs0=engine._faithful_start_states(wide)))
+    lanes = xorshift.lane_table(S)
+    host = np.stack([xorshift.jump_batch(lanes, offset + i * bt).T
+                     for i in range(n_tiles)])
+    assert static.shape == (n_tiles, 4, S)
+    for got in (static, from_ctr, chained):
+        assert np.array_equal(got, host)
 
 
 def test_shift_plan_matches_offset_lease():

@@ -62,7 +62,7 @@ _M64 = (1 << 64) - 1
 TRACED_COUNTS = [0, 1, 5, 1000, (1 << 33) + 7, 1 << 63, _M64, 4096, 37 * 4096,
                  4096 * ((1 << 40) + 3),
                  int(np.random.default_rng(20261019).integers(0, 1 << 63)) * 2 + 1]
-TILE_STRIDE = 256  # _faithful_tile_states' block_t
+TILE_STRIDE = 256  # the default row tile (DEFAULT_BLOCK_T)
 
 
 def _split(n):
@@ -77,7 +77,7 @@ def _host_jumped(states, n):
 @pytest.mark.parametrize("n", TRACED_COUNTS)
 def test_jump_traced_matches_host(rng, n, shape):
     if shape == "vmap16":
-        # As _faithful_tile_states uses it: one table, 16 tile offsets.
+        # Batched under vmap: one table, 16 tile offsets.
         states = rng.integers(0, 1 << 32, (4, 4), dtype=np.uint32)
         tiles = jnp.arange(16, dtype=jnp.uint32)
 
